@@ -1,6 +1,8 @@
 """Benchmark harness — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV.  Sections:
+Prints ``name,us_per_call,derived`` CSV.  A section that raises prints
+a ``<section>/ERROR`` row, the rest still run, and the exit code is
+non-zero.  Sections:
   table1    — 5 algorithms × graph-class suite (paper Table 1)
   sched     — scheduling-mode ablation + cut-off sweep (paper §5.2–5.4)
   profile   — performance profiles (paper Fig. 3)
@@ -13,6 +15,7 @@ Usage: PYTHONPATH=src python -m benchmarks.run [--scale small|bench]
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 
 
@@ -30,6 +33,10 @@ def main(argv=None) -> None:
     )
     args = ap.parse_args(argv)
 
+    from repro.core.compilecache import use_persistent_cache
+
+    use_persistent_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
     from . import lm_roofline, oversub, perf_profile, sched_ablation, table1_graphs
 
     sections = {
@@ -44,6 +51,7 @@ def main(argv=None) -> None:
     chosen = args.only.split(",") if args.only else list(sections)
 
     print("name,us_per_call,derived")
+    failed = []
     for sec in chosen:
         kw = dict(scale=args.scale, repeats=args.repeats)
         if sec in graph_sections:
@@ -53,7 +61,10 @@ def main(argv=None) -> None:
                 print(row)
         except Exception as e:  # noqa: BLE001 — report, continue suite
             print(f"{sec}/ERROR,0.0,{type(e).__name__}: {e}", file=sys.stdout)
+            failed.append(sec)
     sys.stdout.flush()
+    if failed:
+        sys.exit(f"benchmark sections failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

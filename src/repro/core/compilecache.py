@@ -20,9 +20,14 @@ settings, retry policies (:mod:`repro.core.faults`,
 not affect the traced computation, and keying on it would force
 needless retraces (and let a chaos run pollute the cache for the
 fault-free plans that share its steps).
+
+Across processes, :func:`use_persistent_cache` points JAX's on-disk
+compilation cache at a fixed directory.  Entry points call it; nothing
+calls it at import.
 """
 from __future__ import annotations
 
+import os
 from typing import TYPE_CHECKING, Callable, TypeVar
 
 from .. import obs
@@ -30,7 +35,25 @@ from .. import obs
 if TYPE_CHECKING:  # pragma: no cover — typing only, avoids an import cycle
     from .functors import BlockAlgorithm
 
-__all__ = ["alg_cache_key", "shared_entry"]
+__all__ = ["alg_cache_key", "shared_entry", "use_persistent_cache"]
+
+
+def use_persistent_cache(checkout: str) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX, which
+    reads it itself.  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``: the path is part of the cache key, so it
+    is never built from a temporary name, a process id or the time.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 T = TypeVar("T")
 

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .blocks import BlockStore
 from .scheduler import Schedule
@@ -225,6 +225,7 @@ class DistributedEngine:
                 mesh=mesh,
                 in_specs=(P(axis, None), P(axis, None), P(axis, None), P()),
                 out_specs=P(),
+                check_vma=False,
             )
         )
 

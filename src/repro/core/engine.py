@@ -470,8 +470,8 @@ def compile_plan(
 
     ``backend`` selects kernel implementations per the registry
     (``"reference" | "xla" | "pallas"``, default ``"xla"``);
-    ``"pallas"`` falls back to ``"xla"`` when no Pallas runtime is
-    available.  ``use_pallas=True`` is the deprecated spelling of
+    ``"pallas"`` raises when no Pallas runtime is available.
+    ``use_pallas=True`` is the deprecated spelling of
     ``backend="pallas"`` (an explicit ``backend`` wins).  ``share=False``
     opts out of the process-wide compiled-step cache (use it for ad-hoc
     algorithms that reuse a registered name with different kernels).

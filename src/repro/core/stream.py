@@ -209,7 +209,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .. import obs
@@ -477,7 +477,7 @@ class _MeshStreamStep:
                 body, mesh=mesh,
                 in_specs=(P(), P(axis), P(axis), P(), P(), P()),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )(res_ctx, slab, ex_leaves, state0, acc, it)
 
         self._jit = jax.jit(step, static_argnums=(6, 7))
@@ -2153,7 +2153,7 @@ class StreamingPlan:
                 lambda x: jax.tree_util.tree_map(combine_fn("add", axis), x),
                 mesh=self.mesh,
                 in_specs=(PartitionSpec(),), out_specs=PartitionSpec(),
-                check_rep=False,
+                check_vma=False,
             )(t)
 
         fn = jax.jit(allreduce)

@@ -24,12 +24,13 @@ _INT_MAX = np.int32(2**31 - 1)  # numpy scalar: not a captured jax constant
 
 
 def _kernel(a_ref, f_ref, out_ref):
-    a = a_ref[0]                             # (bt, T) tile row panel
-    f = f_ref[0]                             # (T,) frontier mask (float/int)
+    # compare in f32: Mosaic cannot broadcast a packed (bf16) mask
+    a = a_ref[...].astype(jnp.float32)       # (bt, T) tile row panel
+    f = f_ref[...].astype(jnp.float32)       # (1, T) frontier mask
     bt, t = a.shape
     colid = jax.lax.broadcasted_iota(jnp.int32, (bt, t), 1)
-    hit = (a > 0) & (f[None, :] > 0)
-    out_ref[0, :] = jnp.where(hit, colid, _INT_MAX).min(axis=1)
+    hit = (a > 0) & (f > 0)
+    out_ref[...] = jnp.where(hit, colid, _INT_MAX).min(axis=1).reshape(1, bt)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -45,14 +46,15 @@ def frontier_tiles(tiles, fcols, *, block_t: int = 128, interpret: bool = True):
     bt = max(min(block_t, t), 1)
     while t % bt:
         bt -= 1
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid=(nb, t // bt),
         in_specs=[
-            pl.BlockSpec((1, bt, t), lambda b, r: (b, r, 0)),
-            pl.BlockSpec((1, t), lambda b, r: (b, 0)),
+            pl.BlockSpec((None, bt, t), lambda b, r: (b, r, 0)),
+            pl.BlockSpec((None, 1, t), lambda b, r: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bt), lambda b, r: (b, r)),
-        out_shape=jax.ShapeDtypeStruct((nb, t), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, bt), lambda b, r: (b, 0, r)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, t), jnp.int32),
         interpret=interpret,
-    )(tiles, fcols.astype(tiles.dtype))
+    )(tiles, fcols.astype(tiles.dtype).reshape(nb, 1, t))
+    return out.reshape(nb, t)

@@ -17,11 +17,13 @@ Backends
     The hand-tiled Pallas kernels (native on TPU, ``interpret=True``
     elsewhere).
 
-Resolution falls back down the chain ``pallas → xla → reference`` when
-a backend is unavailable or a kernel has no registration for it, so
-``backend="pallas"`` degrades cleanly instead of erroring on hosts
-without a working Pallas lowering.  Dense and sparse paths dispatch
-independently — registration is per kernel name, not global.
+``backend="pallas"`` never degrades quietly: when no Pallas runtime is
+importable :func:`resolve_backend` raises.  The one fallback it keeps is
+per kernel: a kernel with no Pallas registration (the sparse paths have
+none) runs its ``xla`` registration, and never the ``reference`` one.
+``backend="xla"`` falls back to ``reference`` for a kernel with no
+``xla`` registration.  Dense and sparse paths dispatch independently —
+registration is per kernel name, not global.
 """
 from __future__ import annotations
 
@@ -58,17 +60,16 @@ def pallas_available() -> bool:
 
 
 def resolve_backend(backend: str) -> str:
-    """Validate ``backend`` and apply availability fallback.
-
-    ``pallas`` silently degrades to ``xla`` when no Pallas runtime is
-    importable; unknown names raise.
-    """
+    """Validate ``backend``: unknown names raise, and so does ``pallas``
+    when no Pallas runtime is importable (never a quiet ``xla``)."""
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown backend {backend!r}; expected one of {BACKENDS}"
         )
     if backend == "pallas" and not pallas_available():
-        return "xla"
+        raise RuntimeError(
+            "backend='pallas' requested but no Pallas runtime is importable"
+        )
     return backend
 
 
@@ -85,18 +86,18 @@ def register_kernel(name: str, backend: str) -> Callable[[Callable], Callable]:
 
 
 def get_kernel(name: str, backend: str) -> Callable:
-    """Resolve ``name`` for ``backend``, walking the fallback chain."""
+    """Resolve ``name`` for ``backend``: its own registration, else one
+    step down (``pallas → xla``, ``xla → reference``) for a kernel that
+    has no registration for ``backend``; see the module docstring."""
     b = resolve_backend(backend)
-    while True:
-        fn = _REGISTRY.get((name, b))
+    for cand in (b, _FALLBACK.get(b)):
+        fn = _REGISTRY.get((name, cand))
         if fn is not None:
             return fn
-        if b not in _FALLBACK:
-            raise KeyError(
-                f"kernel {name!r} has no registration reachable from "
-                f"backend {backend!r}"
-            )
-        b = _FALLBACK[b]
+    raise KeyError(
+        f"kernel {name!r} has no registration reachable from "
+        f"backend {backend!r}"
+    )
 
 
 def registered(name: str) -> dict[str, Callable]:
@@ -246,6 +247,7 @@ def _tc_workspace(nd: int, tile_dim: int, devices: int = 1) -> int:
 # implementations import lazily inside the wrapper so merely selecting
 # the backend never pays (or breaks on) the Pallas import.
 def _register_builtin() -> None:
+    import jax
     import jax.numpy as jnp
 
     from . import ref
@@ -256,7 +258,9 @@ def _register_builtin() -> None:
 
     @register_kernel("spmv_tiles", "xla")
     def _spmv_xla(tiles, xs):
-        return jnp.einsum("brc,br->bc", tiles, xs)
+        # HIGHEST: a TPU's default f32 matmul rounds xs to bf16
+        return jnp.einsum("brc,br->bc", tiles, xs,
+                          precision=jax.lax.Precision.HIGHEST)
 
     @register_kernel("spmv_tiles", "pallas")
     def _spmv_pallas(tiles, xs):

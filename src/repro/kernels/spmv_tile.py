@@ -15,12 +15,14 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(a_ref, x_ref, y_ref):
-    a = a_ref[0].astype(jnp.float32)        # (T, bt) column panel
-    x = x_ref[0].astype(jnp.float32)        # (T,)
-    y_ref[0, :] = jax.lax.dot_general(
-        x[None, :], a, (((1,), (0,)), ((), ())),
+    a = a_ref[...].astype(jnp.float32)      # (T, bt) column panel
+    x = x_ref[...].astype(jnp.float32)      # (1, T)
+    # HIGHEST: the MXU would otherwise round the f32 ranks to bf16
+    y_ref[...] = jax.lax.dot_general(
+        x, a, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    )[0]
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -29,14 +31,15 @@ def spmv_tiles(tiles, xs, *, block_t: int = 128, interpret: bool = True):
     nb, t, _ = tiles.shape
     bt = min(block_t, t)
     assert t % bt == 0
-    return pl.pallas_call(
+    ys = pl.pallas_call(
         _kernel,
         grid=(nb, t // bt),
         in_specs=[
-            pl.BlockSpec((1, t, bt), lambda b, c: (b, 0, c)),
-            pl.BlockSpec((1, t), lambda b, c: (b, 0)),
+            pl.BlockSpec((None, t, bt), lambda b, c: (b, 0, c)),
+            pl.BlockSpec((None, 1, t), lambda b, c: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bt), lambda b, c: (b, c)),
-        out_shape=jax.ShapeDtypeStruct((nb, t), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, bt), lambda b, c: (b, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, t), jnp.float32),
         interpret=interpret,
-    )(tiles, xs)
+    )(tiles, xs.reshape(nb, 1, t))
+    return ys.reshape(nb, t)

@@ -29,13 +29,13 @@ def _kernel(a_ik_ref, a_jk_ref, a_ij_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = a_ik_ref[0].astype(jnp.float32)   # (bt, T)
-    b = a_jk_ref[0].astype(jnp.float32)   # (bt, T)
-    m = a_ij_ref[0].astype(jnp.float32)   # (bt, bt)
+    a = a_ik_ref[...].astype(jnp.float32)  # (bt, T)
+    b = a_jk_ref[...].astype(jnp.float32)  # (bt, T)
+    m = a_ij_ref[...].astype(jnp.float32)  # (bt, bt)
     w = jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                      # (bt, bt) wedge counts on the MXU
-    out_ref[0, 0] += jnp.sum(w * m)
+    out_ref[...] += jnp.sum(w * m, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -49,12 +49,12 @@ def tc_tiles(a_ik, a_jk, a_ij, *, block_t: int = 128, interpret: bool = True):
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bt, t), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bt, t), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bt, bt), lambda b, i, j: (b, i, j)),
+            pl.BlockSpec((None, bt, t), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, bt, t), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, bt, bt), lambda b, i, j: (b, i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, 1), lambda b, i, j: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, 1), jnp.float32),
         interpret=interpret,
     )(a_ik, a_jk, a_ij)
     return jnp.sum(out)

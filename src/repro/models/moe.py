@@ -130,7 +130,7 @@ def _manual_moe(p, xf, *, top_k, capacity_factor):
 
     Requires a mesh context; falls back to "auto" without one.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .sharding import get_mesh_ctx
@@ -196,7 +196,7 @@ def _manual_moe(p, xf, *, top_k, capacity_factor):
         in_specs=(P(dp_axes, None), P(), P(tp, None, None),
                   P(tp, None, None), P(tp, None, None)),
         out_specs=(P(dp_axes, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(xf, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out, dict(load_balance=aux, z_loss=zloss)
 

@@ -94,7 +94,7 @@ def test_mini_dryrun_8dev_mesh():
     the dry-run machinery end-to-end at test scale."""
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke
         from repro.models.sharding import (
             set_mesh_ctx, param_specs, named_sharding_tree, batch_spec)
@@ -102,7 +102,8 @@ def test_mini_dryrun_8dev_mesh():
             make_train_step, abstract_params, abstract_opt_state)
         from repro.configs.base import ShapeSpec
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ctx = set_mesh_ctx(mesh)
         cfg = get_smoke("qwen2.5-32b")
         p_shapes = abstract_params(cfg)
@@ -138,7 +139,7 @@ def test_mini_dryrun_executes_on_8dev():
     """Not just compile — actually run one sharded train step on 8 devices."""
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke
         from repro.models import lm
         from repro.models.sharding import (
@@ -146,7 +147,8 @@ def test_mini_dryrun_executes_on_8dev():
         from repro.models.steps import make_train_step
         from repro.optim import adamw_init
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ctx = set_mesh_ctx(mesh)
         cfg = get_smoke("qwen2.5-32b")
         with mesh:
@@ -180,6 +182,7 @@ def test_elastic_restore_onto_8dev_mesh():
         from repro.models.steps import make_train_step
         from repro.optim import adamw_init
         from repro.checkpoint import save_checkpoint, restore_checkpoint
+        from jax.sharding import AxisType
 
         cfg = replace(get_smoke("qwen2.5-32b"), dtype="float32")
         params = lm.init_params(cfg, jax.random.key(0))
@@ -187,7 +190,8 @@ def test_elastic_restore_onto_8dev_mesh():
         d = tempfile.mkdtemp()
         save_checkpoint(d, 0, state)  # written host-side (1-device logical)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ctx = set_mesh_ctx(mesh)
         template = jax.eval_shape(lambda: state)
         sh = dict(
@@ -216,11 +220,11 @@ def test_grad_compression_dp_loop_8dev():
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
         from functools import partial
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
+        from jax.sharding import AxisType, Mesh, PartitionSpec as P
         from repro.optim import compressed_psum, error_feedback_init
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         w_true = jnp.asarray(np.random.default_rng(0).standard_normal(16))
 
         def local_grad(w, x):
@@ -231,7 +235,7 @@ def test_grad_compression_dp_loop_8dev():
         @jax.jit
         @partial(shard_map, mesh=mesh,
                  in_specs=(P(), P("data", None, None, None), P()),
-                 out_specs=(P(), P()), check_rep=False)
+                 out_specs=(P(), P()), check_vma=False)
         def step(w, x, r):
             g = local_grad(w, x[0, 0])
             g, r = compressed_psum(dict(w=g), dict(w=r), "data")

@@ -179,20 +179,35 @@ def test_registry_resolution_and_fallback(monkeypatch):
     assert registry.resolve_backend("xla") == "xla"
     with pytest.raises(ValueError):
         registry.resolve_backend("cuda")
+    # a registered Pallas kernel is returned as itself, never its twin
+    assert (registry.get_kernel("spmv_tiles", "pallas")
+            is registry.registered("spmv_tiles")["pallas"])
+    # a kernel with no Pallas registration takes one step, to xla only
+    monkeypatch.setitem(registry._REGISTRY, ("_xla_only", "xla"), len)
+    monkeypatch.setitem(registry._REGISTRY, ("_ref_only", "reference"), abs)
+    assert registry.get_kernel("_xla_only", "pallas") is len
+    assert registry.get_kernel("_ref_only", "xla") is abs
+    with pytest.raises(KeyError):
+        registry.get_kernel("_ref_only", "pallas")
+    # no Pallas runtime: an explicit pallas request raises, not xla
     monkeypatch.setattr(registry, "_FORCE_PALLAS_AVAILABLE", False)
-    assert registry.resolve_backend("pallas") == "xla"
-    # kernel lookup walks the fallback chain too
-    fn = registry.get_kernel("spmv_tiles", "pallas")
-    assert fn is registry.registered("spmv_tiles")["xla"]
+    with pytest.raises(RuntimeError, match="no Pallas runtime"):
+        registry.resolve_backend("pallas")
+    with pytest.raises(RuntimeError, match="no Pallas runtime"):
+        registry.get_kernel("spmv_tiles", "pallas")
 
 
 def test_compile_plan_pallas_falls_back_cleanly(monkeypatch):
+    """Without a Pallas runtime, backend="pallas" fails at compile_plan
+    with a clear error instead of quietly running the xla kernels."""
     monkeypatch.setattr(registry, "_FORCE_PALLAS_AVAILABLE", False)
     g = rmat(7, 8, seed=3)
     store = build_block_store(g, 4)
+    with pytest.raises(RuntimeError, match="no Pallas runtime"):
+        compile_plan(pagerank_algorithm(), store, mode="hybrid",
+                     dense_density=0.001, backend="pallas", share=False)
     plan = compile_plan(pagerank_algorithm(), store, mode="hybrid",
-                        dense_density=0.001, backend="pallas", share=False)
-    assert plan.backend == "xla"
+                        dense_density=0.001, backend="xla", share=False)
     assert abs(np.asarray(plan.run().result).sum() - 1.0) < 1e-3
 
 
