@@ -74,9 +74,11 @@ def _init_factory(seeds):
 
 def _scatter_sparse(ctx, rank, acc):
     src, dst, msk = ctx.src, ctx.dst, ctx.sparse_edge_mask
-    contrib = rank * ctx.extras["inv_deg"]
-    vals = jnp.where(msk, contrib[src], 0.0)
-    return acc.at[dst].add(vals)
+    with jax.named_scope("gather"):
+        contrib = rank * ctx.extras["inv_deg"]
+        vals = jnp.where(msk, contrib[src], 0.0)
+    with jax.named_scope("scatter"):
+        return acc.at[dst].add(vals)
 
 
 def _kernel_sparse(ctx, state, it):

@@ -116,12 +116,17 @@ class _CompiledStep:
         def step(ctx: Context, state, it, run_dense: bool):
             self.traces += 1  # trace-time side effect == compile counter
             obs.metrics.counter("compile.traces").inc()
+            # named scopes reach the device trace as each operation's
+            # op_name; they change no operation
             if kernel_sparse is not None:
-                state = kernel_sparse(ctx, state, it)
+                with jax.named_scope("sparse"):
+                    state = kernel_sparse(ctx, state, it)
             if kernel_dense is not None and run_dense:
-                state = kernel_dense(ctx, state, it)
+                with jax.named_scope("dense"):
+                    state = kernel_dense(ctx, state, it)
             if alg.post is not None:
-                state = alg.post(ctx, state, it)
+                with jax.named_scope("post"):
+                    state = alg.post(ctx, state, it)
             return state
 
         self._jit = jax.jit(step, static_argnums=(3,))

@@ -321,10 +321,13 @@ class _StreamStep:
                     f"{alg.name}: streaming requires a dict state pytree"
                 )
             new = state0
+            # the same named scopes as the in-core step, plus ``fold``
             if kernel_sparse is not None:
-                new = kernel_sparse(ctx, new, it)
+                with jax.named_scope("sparse"):
+                    new = kernel_sparse(ctx, new, it)
             if kernel_dense is not None and run_dense:
-                new = kernel_dense(ctx, new, it)
+                with jax.named_scope("dense"):
+                    new = kernel_dense(ctx, new, it)
             added = set(new) - set(state0)
             if added:  # the in-core step would forward these to post;
                 # per-wave there is no baseline to combine them against
@@ -335,12 +338,13 @@ class _StreamStep:
                     f"scratch attributes there)"
                 )
             out = {}
-            for key in state0:
-                s0, nw = state0[key], new[key]
-                out[key] = (
-                    acc[key] if nw is s0
-                    else _combine_leaf(spec(key), key, acc[key], s0, nw)
-                )
+            with jax.named_scope("fold"):
+                for key in state0:
+                    s0, nw = state0[key], new[key]
+                    out[key] = (
+                        acc[key] if nw is s0
+                        else _combine_leaf(spec(key), key, acc[key], s0, nw)
+                    )
             return out
 
         self._jit = jax.jit(step, static_argnums=(4,))
@@ -357,7 +361,8 @@ class _PostStep:
 
         def step(ctx: Context, state, it):
             self.traces += 1
-            return alg.post(ctx, state, it)
+            with jax.named_scope("post"):
+                return alg.post(ctx, state, it)
 
         self._jit = jax.jit(step)
 
@@ -433,9 +438,11 @@ class _MeshStreamStep:
                 ctx = with_arrays(res_ctx, extras=extras, **arrays)
                 new = state0
                 if kernel_sparse is not None:
-                    new = kernel_sparse(ctx, new, it)
+                    with jax.named_scope("sparse"):
+                        new = kernel_sparse(ctx, new, it)
                 if kernel_dense is not None and run_dense:
-                    new = kernel_dense(ctx, new, it)
+                    with jax.named_scope("dense"):
+                        new = kernel_dense(ctx, new, it)
                 added = set(new) - set(state0)
                 if added:
                     raise ValueError(
@@ -446,29 +453,30 @@ class _MeshStreamStep:
                     )
                 out = {}
                 combined = []
-                for key in state0:
-                    s0, nw = state0[key], new[key]
-                    if nw is s0:
-                        out[key] = acc[key]
-                        continue
-                    kind = spec(key)
-                    if kind not in _COMBINE_KINDS:
-                        raise ValueError(
-                            f"state leaf {key!r} is modified by the kernels "
-                            f"but declares no combine kind in "
-                            f"metadata['combine'] (one of {_COMBINE_KINDS}); "
-                            f"the mesh cannot fold its per-device partials"
+                with jax.named_scope("fold"):
+                    for key in state0:
+                        s0, nw = state0[key], new[key]
+                        if nw is s0:
+                            out[key] = acc[key]
+                            continue
+                        kind = spec(key)
+                        if kind not in _COMBINE_KINDS:
+                            raise ValueError(
+                                f"state leaf {key!r} is modified by the kernels "
+                                f"but declares no combine kind in "
+                                f"metadata['combine'] (one of {_COMBINE_KINDS}); "
+                                f"the mesh cannot fold its per-device partials"
+                            )
+                        red = combine_fn(kind, axis)(
+                            nw - s0 if kind == "add" else nw
                         )
-                    red = combine_fn(kind, axis)(
-                        nw - s0 if kind == "add" else nw
-                    )
-                    if kind == "add":
-                        out[key] = acc[key] + red
-                    elif kind == "min":
-                        out[key] = jnp.minimum(acc[key], red)
-                    else:
-                        out[key] = jnp.maximum(acc[key], red)
-                    combined.append(key)
+                        if kind == "add":
+                            out[key] = acc[key] + red
+                        elif kind == "min":
+                            out[key] = jnp.minimum(acc[key], red)
+                        else:
+                            out[key] = jnp.maximum(acc[key], red)
+                        combined.append(key)
                 self.combined_keys = tuple(combined)
                 return out
 
@@ -600,7 +608,8 @@ class _StagePipeline:
 
     def get(self) -> "_WaveSlab":
         t0 = time.perf_counter()
-        slab = self._q.get()
+        with obs.span("stage_wait", lane="main"):
+            slab = self._q.get()
         self.stall_s += time.perf_counter() - t0
         if slab is None:
             # the worker died; mark it so the watchdog fails over to
@@ -668,7 +677,8 @@ class _HostLane:
         self._cpu = jax.devices("cpu")[0]
         store = plan.store
         t0 = time.perf_counter()
-        with jax.default_device(self._cpu):
+        with obs.span("host_lane_build", lane="main", units=len(self.units)), \
+                jax.default_device(self._cpu):
             # global CSR views: converted to CPU-committed jax arrays
             # ONCE and shared by every unit context (eager lax.cond
             # traces both kernel branches, so even csr="none"
@@ -1243,30 +1253,32 @@ class StreamingPlan:
         streaming pipeline (empty waves vanish), peeled
         ``host_task_ids`` become host-lane execution units, and the
         lane (thread pool + per-unit CPU contexts) is rebuilt."""
-        if self._host_lane is not None:
-            self._host_lane.close()
-            self._host_lane = None
-        self._host_units = [w.host_task_ids for w in waves
-                            if w.host_task_ids.size]
-        dev_waves = [w for w in waves if w.task_ids.size]
-        self._slabs = self._plan_recipes(dev_waves, initial=initial)
-        edge_free = int(self.alg.metadata.get("edge_free_iterations", 0))
-        if (self._host_units and not self._slabs and not self._hoisted
-                and self.alg.prepare is not None
-                and (self.alg.post is not None or edge_free > 0)):
-            # fully host-peeled plan (host_fraction=1.0): post / the
-            # edge-free phase still run against the resident context,
-            # whose extras are normally hoisted from the device waves'
-            # prepare outputs — no device wave exists here, so prepare
-            # runs once against the full store instead
-            extras = _to_host(self.alg.run_prepare(
-                self.store, self.schedule, self._plan_state))
-            extras.pop("__workspace_bytes__", None)
-            self._resident_extras = extras
-            self._hoisted = True
-        if self._host_units:
-            self._host_lane = _HostLane(self, self._host_units)
-        self.schedule.stats["waves"] = len(self._slabs)
+        with obs.span("plan_waves", lane="main", initial=initial,
+                      waves=len(waves)):
+            if self._host_lane is not None:
+                self._host_lane.close()
+                self._host_lane = None
+            self._host_units = [w.host_task_ids for w in waves
+                                if w.host_task_ids.size]
+            dev_waves = [w for w in waves if w.task_ids.size]
+            self._slabs = self._plan_recipes(dev_waves, initial=initial)
+            edge_free = int(self.alg.metadata.get("edge_free_iterations", 0))
+            if (self._host_units and not self._slabs and not self._hoisted
+                    and self.alg.prepare is not None
+                    and (self.alg.post is not None or edge_free > 0)):
+                # fully host-peeled plan (host_fraction=1.0): post / the
+                # edge-free phase still run against the resident context,
+                # whose extras are normally hoisted from the device waves'
+                # prepare outputs — no device wave exists here, so prepare
+                # runs once against the full store instead
+                extras = _to_host(self.alg.run_prepare(
+                    self.store, self.schedule, self._plan_state))
+                extras.pop("__workspace_bytes__", None)
+                self._resident_extras = extras
+                self._hoisted = True
+            if self._host_units:
+                self._host_lane = _HostLane(self, self._host_units)
+            self.schedule.stats["waves"] = len(self._slabs)
 
     def _make_unit(self, wave: Wave) -> "_PlanUnit":
         """Assemble one wave into a planning unit (raw extras kept)."""
@@ -2303,8 +2315,10 @@ class StreamingPlan:
             # timings, and a rebalance fired inside _calibrate may
             # rebuild the host lane — in-flight futures must be done
             acc = self._gather_host(host_futs, acc)
-            acc = self._calibrate(state0, acc, iarr, it)
-            self._maybe_refresh_split(it)
+            with obs.span("calibrate", lane="main", it=it, waves=nw):
+                acc = self._calibrate(state0, acc, iarr, it)
+            with obs.span("split_refresh", lane="main", it=it) as sp:
+                sp.set(applied=self._maybe_refresh_split(it))
             return acc, 0.0
         t0 = time.perf_counter()
         put0 = self._phase["device_put"]
@@ -2386,7 +2400,8 @@ class StreamingPlan:
         into the running accumulator; publishes the host metrics."""
         if futs is None:
             return acc
-        results = [f.result() for f in futs]
+        with obs.span("host_wait", lane="main", units=len(futs)):
+            results = [f.result() for f in futs]
         self._host_futs = None
         acc, busy_s = self._host_lane.fold(results, acc)
         self._phase["host_compute"] += busy_s
@@ -2574,7 +2589,7 @@ class StreamingPlan:
         self._edge_free_bufs = None
         obs.instant("host_disable", lane="resilience")
 
-    def _maybe_refresh_split(self, it: int) -> None:
+    def _maybe_refresh_split(self, it: int) -> bool:
         """Adapt the ``"auto"`` host/device split to measured times.
 
         Runs right after each calibration pass.  Per-task device-
@@ -2593,21 +2608,21 @@ class StreamingPlan:
         (``REPRO_HETERO_NOISE_FLOOR_S``) the split deterministically
         stays at its current value.  Each application invalidates the
         calibration, so the re-packed device waves are re-timed before
-        the next evaluation."""
+        the next evaluation.  Returns whether a new split was applied."""
         if self._host_frac != "auto" or not self._host_capable:
-            return
+            return False
         if it + 1 >= self.alg.max_iterations:
-            return                      # no later iteration would run it
+            return False            # no later iteration would run it
         cal = self._calibration
         if cal is None or not self._slabs:
-            return                      # a rebalance just re-packed
+            return False            # a rebalance just re-packed
         wave_s = list(cal.get("wave_compute_s", []))
         if not wave_s or float(np.mean(wave_s)) < _hetero_noise_floor_s():
-            return
+            return False
         dev_w = float(sum(self.schedule.weights[s.wave.task_ids].sum()
                           for s in self._slabs))
         if dev_w <= 0.0:
-            return
+            return False
         dev_rate = float(sum(wave_s)) / dev_w
         busy_s = getattr(self, "_last_host_busy_s", 0.0)
         if self._host_units and busy_s > 0.0 and dev_rate > 0.0:
@@ -2640,7 +2655,7 @@ class StreamingPlan:
             np.concatenate(self._host_units)) if self._host_units else 0.0)
         if not (hetero_split_diverged(cur_split, new_split)
                 or (new_split == 0.0) != (cur_split == 0.0)):
-            return
+            return False
         self._apply_waves(waves)
         self._edge_free_bufs = None     # stale slab-0 reference
         self._hetero_refreshes += 1
@@ -2648,6 +2663,7 @@ class StreamingPlan:
         obs.instant("hetero_refresh", lane="main", split=float(new_split),
                     host_tasks=int(sum(u.size for u in self._host_units)),
                     waves=len(self._slabs))
+        return True
 
     def _hetero_stats(self, phase_delta: dict) -> dict:
         """The ``schedule_stats["hetero"]`` block: the resolved

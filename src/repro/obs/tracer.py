@@ -37,6 +37,14 @@ Optional JAX bridge
 the same name, so host spans line up with device activity in profiles
 captured via ``jax.profiler.trace``.  The bridge degrades to a no-op
 when the profiler is unavailable.
+
+While the bridge is on, JAX's persistent compilation cache is keyed on
+each operation's metadata too (``jax.named_scope`` names, source
+locations).  JAX otherwise keys it on the program stripped of its
+metadata, so a step found in a shared cache, compiled by code that
+named other scopes, would come back under those names and the profile
+would misname its kernels.  With the bridge off the default key, and
+its cache hits, stay as they were.
 """
 from __future__ import annotations
 
@@ -185,6 +193,10 @@ class _Span:
         self._start = time.perf_counter_ns()
         return self
 
+    def set(self, **args) -> None:
+        """Add attributes known only once the span's work has run."""
+        self._args.update(args)
+
     def __exit__(self, *exc) -> bool:
         end = time.perf_counter_ns()
         if self._jax is not None:
@@ -215,12 +227,36 @@ class _NoopSpan:
     def __enter__(self) -> "_NoopSpan":
         return self
 
+    def set(self, **args) -> None:
+        pass
+
     def __exit__(self, *exc) -> bool:
         return False
 
 
 _NOOP = _NoopSpan()
 _tracer: Tracer | None = None
+#: JAX's ``compilation_cache_include_metadata_in_key`` from before the
+#: bridge turned it on; None while the bridge is off
+_metadata_key_was: bool | None = None
+
+
+def _key_cache_on_metadata() -> None:
+    """Key JAX's persistent compilation cache on metadata exactly while
+    the active tracer bridges spans into the profiler (module docs)."""
+    global _metadata_key_was
+    on = _tracer is not None and _tracer.jax_annotations
+    if on == (_metadata_key_was is not None):
+        return
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    if on:
+        _metadata_key_was = bool(getattr(jax.config, flag))
+        jax.config.update(flag, True)
+    else:
+        jax.config.update(flag, _metadata_key_was)
+        _metadata_key_was = None
 
 
 def enabled() -> bool:
@@ -249,6 +285,7 @@ def enable(capacity: int = 65536, *,
                 not in _FALSY
             )
         _tracer = Tracer(capacity, jax_annotations=jax_annotations)
+        _key_cache_on_metadata()
     return _tracer
 
 
@@ -256,6 +293,7 @@ def disable() -> None:
     """Turn tracing off; already-recorded spans are discarded."""
     global _tracer
     _tracer = None
+    _key_cache_on_metadata()
 
 
 class tracing:
@@ -275,6 +313,7 @@ class tracing:
     def __exit__(self, *exc) -> bool:
         global _tracer
         _tracer = self._prev
+        _key_cache_on_metadata()
         return False
 
 

@@ -142,6 +142,36 @@ def test_tracing_context_restores_previous_state():
         obs.disable()
 
 
+@pytest.mark.parametrize("default", [False, True])
+def test_profiler_bridge_keys_the_compile_cache_on_metadata(default):
+    """While spans reach the profiler, a step from JAX's persistent cache
+    carries the names its own code gave; without the bridge the key, and
+    the cache hits, stay as JAX's default."""
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, default)
+    try:
+        obs.enable()
+        assert getattr(jax.config, flag) is default
+        obs.disable()
+        obs.enable(jax_annotations=True)
+        assert getattr(jax.config, flag) is True
+        with obs.tracing(jax_annotations=False):
+            assert getattr(jax.config, flag) is default
+        assert getattr(jax.config, flag) is True
+        obs.enable(jax_annotations=True)        # idempotent
+        obs.disable()
+        assert getattr(jax.config, flag) is default
+        with obs.tracing(jax_annotations=True):
+            assert getattr(jax.config, flag) is True
+        assert getattr(jax.config, flag) is default
+    finally:
+        obs.disable()
+        jax.config.update(flag, before)
+
+
 # --------------------------------------------------------------- metrics
 def test_counter_and_gauge_semantics():
     reg = obs.MetricsRegistry()
@@ -290,10 +320,17 @@ def test_streamed_span_tree_exact(graph):
     assert counts["device_put"] == expect
     assert counts["compute"] == expect
     assert "collective" not in counts   # no mesh, no collective spans
-    # phase spans nest under their iteration on the main thread
+    # phase spans nest under the calibration pass of the first
+    # iteration, and under their iteration after it, on the main thread
+    assert counts["calibrate"] == counts["split_refresh"] == 1
+    parents: dict = {}
     for ev in events:
         if ev.name in ("device_put", "compute", "assemble"):
-            assert ev.parent == "iteration"
+            parents[ev.parent] = parents.get(ev.parent, 0) + 1
+        if ev.name in ("calibrate", "split_refresh"):
+            assert ev.parent == "iteration" and ev.args["it"] == 0
+    assert parents == {"calibrate": 3 * 2 * W,
+                       "iteration": 3 * (I - 1) * W}
     lanes = {ev.name: ev.lane for ev in events}
     assert lanes["assemble"] == "staging"
     assert lanes["device_put"] == "device"
