@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.functors import BlockAlgorithm, Mode
+from ..core.runs import gather_src
 from ..kernels import get_kernel
 
 __all__ = ["pagerank_algorithm", "pagerank"]
@@ -73,12 +74,11 @@ def _init_factory(seeds):
 
 
 def _scatter_sparse(ctx, rank, acc):
-    src, dst, msk = ctx.src, ctx.dst, ctx.sparse_edge_mask
     with jax.named_scope("gather"):
         contrib = rank * ctx.extras["inv_deg"]
-        vals = jnp.where(msk, contrib[src], 0.0)
+        vals = jnp.where(ctx.sparse_edge_mask, gather_src(ctx, contrib), 0.0)
     with jax.named_scope("scatter"):
-        return acc.at[dst].add(vals)
+        return acc.at[ctx.dst].add(vals)
 
 
 def _kernel_sparse(ctx, state, it):
@@ -174,9 +174,10 @@ def pagerank_algorithm(*, damping: float = 0.85, tol: float = 1e-4,
         # traces against it — two tolerances must not share a step.
         # seeds stay OUT of params: personalization is state content
         # (restart leaf), so every seed set shares one compiled step.
+        # src="gather": arc sources are read only through gather_src.
         metadata=dict(combine="add", params=dict(damping=damping, tol=tol),
                       workspace_kernel="spmv_tiles", csr="none",
-                      mesh="shard", batch="query"),
+                      src="gather", mesh="shard", batch="query"),
     )
 
 

@@ -219,13 +219,13 @@ class BlockStore:
         return self.tiles[pos], self.tile_row_start[pos], self.tile_col_start[pos]
 
     # ------------------------------------------------------------------
-    def device_arrays(self) -> dict:
+    def device_arrays(self, *, with_src: bool = True) -> dict:
         """jnp views of the store for jitted kernels (lazy import keeps the
-        host-side path numpy-only)."""
+        host-side path numpy-only); ``with_src=False`` leaves ``src`` on
+        the host."""
         import jax.numpy as jnp
 
         out = dict(
-            src=jnp.asarray(self.src),
             dst=jnp.asarray(self.dst),
             edge_block=jnp.asarray(self.edge_block),
             indptr=jnp.asarray(self.indptr),
@@ -233,6 +233,8 @@ class BlockStore:
             degrees=jnp.asarray(self.degrees),
             row_block_ptr=jnp.asarray(self.row_block_ptr),
         )
+        if with_src:
+            out["src"] = jnp.asarray(self.src)
         if self.tile_block_ids.size:
             out.update(
                 tiles=jnp.asarray(self.tiles),

@@ -34,12 +34,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .runs import expand_runs, source_runs
+
 if TYPE_CHECKING:  # pragma: no cover — import cycle guard, typing only
     from .blocks import BlockStore
     from .scheduler import Schedule
 
 __all__ = ["Context", "HostCtx", "build_context", "build_host_ctx",
-           "with_extras", "with_arrays"]
+           "with_extras", "with_arrays", "with_arc_sources"]
 
 
 # Device-array fields, in flatten order.  ``tiles``/``tile_*`` are None
@@ -47,7 +49,7 @@ __all__ = ["Context", "HostCtx", "build_context", "build_host_ctx",
 _ARRAY_FIELDS = (
     "src", "dst", "edge_block", "indptr", "indices", "degrees",
     "row_block_ptr", "cuts", "sparse_edge_mask", "dense_edge_mask",
-    "tiles", "tile_row_start", "tile_col_start",
+    "tiles", "tile_row_start", "tile_col_start", "run_pos", "run_src",
 )
 _STATIC_FIELDS = ("n", "m", "p", "tile_dim", "backend")
 
@@ -78,7 +80,7 @@ class Context:
     """
 
     # --- segmented COO + CSR views of the store -----------------------
-    src: Any
+    src: Any                  # None where run_pos/run_src replace it
     dst: Any
     edge_block: Any
     indptr: Any
@@ -93,6 +95,9 @@ class Context:
     tiles: Any = None
     tile_row_start: Any = None
     tile_col_start: Any = None
+    # --- runs of equal sources, in ``src``'s place (see core.runs) -----
+    run_pos: Any = None
+    run_src: Any = None
     # --- per-algorithm prepare outputs --------------------------------
     extras: dict[str, Any] = field(default_factory=dict)
     # --- static scalars (jit cache key, not traced) -------------------
@@ -158,21 +163,26 @@ class HostCtx:
 # ----------------------------------------------------------------------
 def build_context(store: "BlockStore", schedule: "Schedule", *,
                   backend: str = "xla",
-                  extras: dict[str, Any] | None = None) -> Context:
+                  extras: dict[str, Any] | None = None,
+                  src_runs: bool = False) -> Context:
     """Assemble the device-side :class:`Context` for one (store, schedule).
 
     Mirrors what the legacy ``Engine._build_context`` produced, minus the
     host objects: segmented-COO/CSR device views, the static edge→path
     routing masks derived from the schedule's dense selection, and the
-    conformal cut vector.
+    conformal cut vector.  With ``src_runs`` (the algorithm's
+    ``metadata["src"] == "gather"``), the runs of equal sources
+    (:func:`repro.core.runs.source_runs`) take ``src``'s place wherever
+    they are no larger than it.
     """
-    arrays = store.device_arrays()
+    runs = source_runs(store.src) if src_runs else None
+    arrays = store.device_arrays(with_src=runs is None)
     dense_blocks = np.zeros(store.layout.num_blocks, dtype=bool)
     if schedule.dense_block_ids.size:
         dense_blocks[schedule.dense_block_ids] = True
     edge_dense = dense_blocks[np.asarray(store.edge_block)]
     return Context(
-        src=arrays["src"],
+        src=arrays.get("src"),
         dst=arrays["dst"],
         edge_block=arrays["edge_block"],
         indptr=arrays["indptr"],
@@ -185,6 +195,8 @@ def build_context(store: "BlockStore", schedule: "Schedule", *,
         tiles=arrays.get("tiles"),
         tile_row_start=arrays.get("tile_row_start"),
         tile_col_start=arrays.get("tile_col_start"),
+        run_pos=None if runs is None else jnp.asarray(runs[0]),
+        run_src=None if runs is None else jnp.asarray(runs[1]),
         extras=dict(extras or {}),
         n=store.n,
         m=store.m,
@@ -223,6 +235,18 @@ def with_arrays(ctx: Context, **arrays: Any) -> Context:
     if unknown:
         raise TypeError(f"unknown Context array fields: {sorted(unknown)}")
     return replace(ctx, **arrays)
+
+
+def with_arc_sources(ctx: Context) -> Context:
+    """``ctx`` with ``src`` expanded from its runs where they took its
+    place, so that a kernel that reads ``ctx.src`` still reads the
+    sources.  Inside a compiled step the compiler drops the expansion
+    where no kernel reads it; where one does, it costs a prefix sum over
+    the arcs per step."""
+    if ctx.src is not None or ctx.run_src is None:
+        return ctx
+    return replace(ctx, src=expand_runs(ctx.run_pos, ctx.run_src,
+                                        ctx.dst.shape[0]))
 
 
 def with_extras(ctx: Context, extras: dict[str, Any]) -> Context:

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from .. import obs
 from .blocks import BlockStore
 from .compilecache import alg_cache_key, shared_entry
-from .context import Context, HostCtx, build_context, build_host_ctx
+from .context import (Context, HostCtx, build_context, build_host_ctx,
+                      with_arc_sources)
 from .direction import DirectionController, kernels_for, resolve_direction
 from .faults import FaultPlan
 from .functors import BlockAlgorithm
@@ -116,6 +117,7 @@ class _CompiledStep:
         def step(ctx: Context, state, it, run_dense: bool):
             self.traces += 1  # trace-time side effect == compile counter
             obs.metrics.counter("compile.traces").inc()
+            ctx = with_arc_sources(ctx)
             # named scopes reach the device trace as each operation's
             # op_name; they change no operation
             if kernel_sparse is not None:
@@ -149,6 +151,16 @@ def _compiled_step_for(alg: BlockAlgorithm, backend: str, *,
                        direction: str = "push") -> _CompiledStep:
     return shared_entry(_STEP_CACHE, alg_cache_key(alg, backend, direction),
                         lambda: _CompiledStep(alg, direction), share=share)
+
+
+def _sparse_gather(ctx: Context) -> dict:
+    """How many elements the source gather of an algorithm that declares
+    ``metadata["src"] == "gather"`` reads: one per run of equal sources
+    where the context holds runs, one per arc otherwise."""
+    engaged = ctx.run_src is not None
+    return dict(arcs=ctx.m,
+                gathered=int(ctx.run_src.shape[0]) if engaged else ctx.m,
+                engaged=engaged)
 
 
 # ----------------------------------------------------------------------
@@ -251,8 +263,9 @@ class Plan:
         binding = _Binding(
             store=store,
             schedule=sched,
-            context=build_context(store, sched, backend=self.backend,
-                                  extras=extras),
+            context=build_context(
+                store, sched, backend=self.backend, extras=extras,
+                src_runs=self.alg.metadata.get("src") == "gather"),
             host=build_host_ctx(store, sched, backend=self.backend),
             run_dense=(
                 self.alg.kernel_dense is not None
@@ -357,6 +370,11 @@ class Plan:
         m.counter("engine.runs").inc()
         m.counter("engine.iterations").inc(it)
         m.histogram("engine.run_seconds").observe(dt)
+        gather = (_sparse_gather(b.context)
+                  if alg.metadata.get("src") == "gather" else None)
+        if gather and gather["gathered"]:
+            m.gauge("sparse.arcs_per_gather").set(
+                gather["arcs"] / gather["gathered"])
         if self._faults is not None:
             new = self._faults.injected - self._injected_pub
             if new > 0:
@@ -364,6 +382,8 @@ class Plan:
                 self._injected_pub = self._faults.injected
         result = alg.finalize(b.store, state) if alg.finalize else state
         stats = b.schedule.stats
+        if gather is not None:
+            stats = dict(stats, sparse_gather=gather)
         if ctrl is not None:
             stats = dict(stats, direction=ctrl.stats())
         # only runs that opted into fault tolerance (or actually

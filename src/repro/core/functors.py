@@ -46,6 +46,10 @@ loop continues while it returns ``True``, bounded by
     ``"slice"`` (wave-staged conformal CSR row slices) | ``"none"``
     (kernels never read ``ctx.indices``) | ``"resident"`` (default:
     full CSR stays on device — unbounded by the budget).
+``src``
+    ``"gather"``: kernels read arc sources only through
+    :func:`repro.core.runs.gather_src`, so the in-core context may hold
+    runs of equal sources in ``src``'s place (absent: it keeps ``src``).
 ``workspace_kernel``
     registry kernel naming the dense path's scratch estimator.
 ``edge_free_iterations``
