@@ -26,7 +26,10 @@ so pull levels stay bit-identical to push.  The default (no
 ``direction=``) is fixed push.  Activation is realized as masking (see
 DESIGN §2): inactive edges/vertices are masked out rather than
 compacted, which is the static-shape analog of composing block-lists
-from blocks with non-empty queues.
+from blocks with non-empty queues.  Every level kernel, push or pull,
+names its per-arc (or per-tile-row) flag reads and masks ``gather`` and
+its min-scatter of parent offers ``scatter`` (``jax.named_scope``,
+inside the executor's ``sparse``/``dense`` scope), as PageRank does.
 
 Batch axis (``sources=[...]``): the state carries a leading query axis
 on ``parent``/``frontier``/``dist`` (and the per-query count ``nf``),
@@ -103,12 +106,12 @@ def _top_down(ctx, state, edge_mask):
     # guard sees iteration-start state no matter how the level's edge
     # work is split (sparse→dense chaining in-core, waves streamed) and
     # the level's min-scatter is order-independent.
-    unvisited = state["dist"] == _UNVISITED
-    do = edge_mask & frontier[src] & unvisited[dst]
-    tgt = jnp.where(do, dst, n)
-    cand = jnp.where(do, src, _UNVISITED)
-    ppad = jnp.concatenate([parent, jnp.asarray([_UNVISITED], jnp.int32)])
-    return ppad.at[tgt].min(cand)[:n]
+    with jax.named_scope("gather"):
+        unvisited = state["dist"] == _UNVISITED
+        do = edge_mask & frontier[src] & unvisited[dst]
+        tgt = jnp.where(do, dst, n)
+        cand = jnp.where(do, src, _UNVISITED)
+    return _min_scatter(parent, tgt, cand)
 
 
 def _bottom_up_edges(ctx, state, edge_mask):
@@ -119,12 +122,22 @@ def _bottom_up_edges(ctx, state, edge_mask):
     src, dst = ctx.src, ctx.dst
     parent, frontier = state["parent"], state["frontier"]
     n = parent.shape[0]
-    unvisited = state["dist"] == _UNVISITED  # see _top_down
-    do = edge_mask & unvisited[src] & frontier[dst]
-    tgt = jnp.where(do, src, n)
-    cand = jnp.where(do, dst, _UNVISITED)
-    ppad = jnp.concatenate([parent, jnp.asarray([_UNVISITED], jnp.int32)])
-    return ppad.at[tgt].min(cand)[:n]
+    with jax.named_scope("gather"):
+        unvisited = state["dist"] == _UNVISITED  # see _top_down
+        do = edge_mask & unvisited[src] & frontier[dst]
+        tgt = jnp.where(do, src, n)
+        cand = jnp.where(do, dst, _UNVISITED)
+    return _min_scatter(parent, tgt, cand)
+
+
+def _min_scatter(parent, tgt, cand):
+    """The level's parent offers folded by minimum; target ``n`` (one
+    past the last vertex) takes the masked-out offers."""
+    n = parent.shape[0]
+    with jax.named_scope("scatter"):
+        ppad = jnp.concatenate([parent,
+                                jnp.asarray([_UNVISITED], jnp.int32)])
+        return ppad.at[tgt].min(cand)[:n]
 
 
 # the state leaves a level function reads; batched kernels vmap over
@@ -167,12 +180,12 @@ def _bottom_up_tiles(ctx, state):
     )
     rows = ctx.tile_row_start[:, None] + jnp.arange(t)[None, :]
     rows = jnp.minimum(rows, n)            # tile rows past n are padding
-    unvisited_pad = jnp.concatenate(
-        [state["dist"] == _UNVISITED, jnp.asarray([False])]  # see _top_down
-    )
-    cand = jnp.where(unvisited_pad[rows], cand, _UNVISITED)
-    ppad = jnp.concatenate([parent, jnp.asarray([_UNVISITED], jnp.int32)])
-    return ppad.at[rows].min(cand)[:n]
+    with jax.named_scope("gather"):
+        unvisited_pad = jnp.concatenate(
+            [state["dist"] == _UNVISITED, jnp.asarray([False])]  # see _top_down
+        )
+        cand = jnp.where(unvisited_pad[rows], cand, _UNVISITED)
+    return _min_scatter(parent, rows, cand)
 
 
 _kernel_sparse = _level_kernel(

@@ -40,9 +40,10 @@ hysteresis band like the hetero split / tail rebalancer):
   the threshold cannot flap.
 
 ``REPRO_DIRECTION_BETA`` / ``REPRO_DIRECTION_HYSTERESIS`` override the
-knobs; every decision lands in ``schedule_stats["direction"]`` and each
-flip increments the ``stream.direction_switches`` counter and drops an
-instant on the ``direction`` tracer lane.  See
+knobs; every decision lands in ``schedule_stats["direction"]`` and
+bumps the ``direction.push_levels`` or ``direction.pull_levels``
+counter, and each flip increments the ``stream.direction_switches``
+counter and drops an instant on the ``direction`` tracer lane.  See
 ``docs/performance.md`` ("Direction optimization") for tuning and
 ``docs/writing-algorithms.md`` for the authoring contract.
 """
@@ -245,6 +246,10 @@ class DirectionController:
             obs.metrics.counter("stream.direction_switches").inc()
             obs.instant("direction_switch", lane="direction",
                         it=it, to=d, density=density)
+        if d == "pull":
+            obs.metrics.counter("direction.pull_levels").inc()
+        else:
+            obs.metrics.counter("direction.push_levels").inc()
         self.current = d
         self.decisions.append(d)
         self.densities.append(density)
